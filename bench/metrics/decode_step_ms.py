@@ -1,0 +1,16 @@
+"""Model dispatch: device milliseconds per execution of the decode-only
+step program (``zoo.decode_paged``), from the trace's module events,
+mean over executions on every chip."""
+
+from bench.trace import matching
+
+PATTERNS = (r"_decode_paged_impl",)
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    evs = [e for c in run.trace.chips for e in matching(c.modules, PATTERNS)]
+    if not evs:
+        return None
+    return 1e3 * sum(e - s for _, s, e in evs) / len(evs)
