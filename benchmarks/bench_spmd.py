@@ -14,9 +14,8 @@ FIXED, and fails loudly unless:
     at fixed per-chip HBM (PRISM-style pooling: each chip holds a
     1/chips slice of every page).
 
-Prints the per-run table plus the per-shard breakdown (DMA seconds,
-blocked-on-collective seconds, per-shard resident pool tokens via the
-§12 telemetry registry); results land in
+Prints the per-run table plus the per-shard resident pool tokens (via
+the §12 telemetry registry); results land in
 results/bench/bench_spmd.{csv,json}.
 """
 
@@ -137,8 +136,6 @@ def main() -> None:
             "device_pool_tokens": toks,
             "per_chip_tokens": PER_CHIP_TOKENS,
             "reused_tokens": eng.stats["reused_tokens"],
-            "shard_dma_s": eng.stats["shard_dma_seconds"],
-            "collective_s": eng.stats["collective_seconds"],
         })
         for s in range(chips if chips > 1 else 0):   # no shards w/o mesh
             g = tel.registry.get("engine_shard_pool_tokens",
@@ -146,8 +143,6 @@ def main() -> None:
             shard_rows.append({
                 "chips": chips, "shard": s,
                 "pool_tokens": (g if g is not None else 0),
-                "shard_dma_s": eng.stats["shard_dma_seconds"],
-                "collective_s": eng.stats["collective_seconds"],
             })
 
     emit("bench_spmd", rows)

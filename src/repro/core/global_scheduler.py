@@ -240,6 +240,12 @@ class GlobalScheduler:
     # ---- the scheduling entry point -------------------------------------------
 
     def schedule(self, request: Request, now: float) -> ScheduleDecision:
+        # serving imports core: the span helper binds at call time
+        from ..serving.telemetry import host_span
+        with host_span("preble.e2.schedule", request=request.request_id):
+            return self._schedule(request, now)
+
+    def _schedule(self, request: Request, now: float) -> ScheduleDecision:
         cfg = self.config
         match = self.tree.match(request.tokens, now=now, update_stats=True)
         decision = e2_schedule(self.instances, self.tree, match,

@@ -98,6 +98,8 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lens, *,
     acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        # the kernel's own name in compiled programs and profiles
+        name="paged_decode_attention",
         out_shape=[
             jax.ShapeDtypeStruct((B, KH, P, G, D), jnp.float32),
             jax.ShapeDtypeStruct((B, KH, P, G, 1), jnp.float32),

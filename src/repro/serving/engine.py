@@ -54,7 +54,6 @@ or one per TP submesh, with static dispatch shapes compiled at warm-up.
 from __future__ import annotations
 
 import dataclasses
-import time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -76,7 +75,7 @@ from .faults import CircuitBreaker, InstanceCrashed
 from .kv_cache import PagedKVPool
 from .kv_offload import HostKVStore, PagedHostTier
 from .speculative import DraftWorker, SpeculativeConfig
-from .telemetry import StatsDict, frac_of
+from .telemetry import HOST_SPANS, StatsDict, frac_of, host_span
 
 Pytree = Any
 
@@ -254,13 +253,10 @@ class Engine:
              "prefetch_wasted": 0, "prefetch_dispatches": 0,
              "prefetch_batches": 0,
              "prefetch_batches_overlapped": 0,
-             # SPMD plane (§13): wall seconds of per-shard host<->device
-             # payload movement (batch lowering, restore/prefetch
-             # scatters, demote drains) and of blocking on the sharded
-             # dispatch + cross-shard result assembly. Accumulated ONLY
-             # when a mesh exists — single-chip engines stay at 0.0 and
-             # byte-identical to the pre-SPMD plane.
-             "shard_dma_seconds": 0.0, "collective_seconds": 0.0,
+             # pure-decode dispatches: lanes dispatched, and those of
+             # them that carried padding (mixed steps count theirs in
+             # fused_padded_tokens)
+             "decode_only_lanes": 0, "decode_only_padded_lanes": 0,
              # speculative decoding (§14): target-side verify outcomes.
              # spec_draft_dispatches counts the DRAFT model's fused
              # propose dispatches — they never touch model_dispatches,
@@ -277,6 +273,12 @@ class Engine:
                      "spec_acceptance_frac":
                      frac_of("spec_accepted_tokens",
                              "spec_proposed_tokens")})
+        # profiler span names (telemetry.HOST_SPANS) for this instance,
+        # built once: the step path formats no strings
+        self._span = {name.rsplit(".", 1)[1]:
+                      f"{name}.{econf.instance_id}"
+                      for name in HOST_SPANS
+                      if name.startswith("preble.engine.")}
         self.telemetry = None
         self.failed = False
         # fault injection (DESIGN.md §11): None on fault-free runs —
@@ -350,7 +352,9 @@ class Engine:
         else:
             self.pages = _cache_zeros(specs)
         # params ride as argument 0 of every model dispatch: closed over,
-        # they would be baked into each executable as constants
+        # they would be baked into each executable as constants.
+        # Profiler traces find the step programs by the names
+        # _decode_paged_impl and _mixed_paged_impl: keep them.
         self._decode_paged_fn = jax.jit(self._decode_paged_impl,
                                         donate_argnums=(1,), **jit_kw)
         self._extend_paged_fn = jax.jit(self._extend_paged_impl,
@@ -473,29 +477,19 @@ class Engine:
     def _lower_batch(self, wb: ModelWorkerBatch) -> ForwardBatch:
         """ModelWorkerBatch -> ForwardBatch: ONE host->device transfer
         for the step's dense inputs. On a submesh the arrays commit
-        replicated (timed into ``shard_dma_seconds``); a single-chip
-        engine commits them to its own device."""
-        if self.mesh is None:
-            return ForwardBatch.lower(wb, self._device_sharding)
-        t0 = time.perf_counter()
-        fb = ForwardBatch.lower(wb, self._rep_sharding)
-        jax.block_until_ready(fb.dec_page_table)
-        self.stats["shard_dma_seconds"] += time.perf_counter() - t0
-        return fb
+        replicated; a single-chip engine commits them to its own
+        device."""
+        with host_span(self._span["lower"]):
+            return ForwardBatch.lower(
+                wb, self._device_sharding if self.mesh is None
+                else self._rep_sharding)
 
-    def _fetch_result(self, nxt) -> np.ndarray:
-        """Materialize the dispatch's per-lane predictions host-side.
-        On a submesh this blocks on the sharded computation and
-        assembles the cross-shard result (timed into
-        ``collective_seconds`` — an emulated mesh cannot split the
-        collective out of the fused dispatch, so the series reports the
-        blocked-on-device wall time, an upper bound)."""
-        if self.mesh is None:
-            return np.asarray(nxt)
-        t0 = time.perf_counter()
-        out = np.asarray(nxt)
-        self.stats["collective_seconds"] += time.perf_counter() - t0
-        return out
+    def _fetch_result(self, *outs) -> List[np.ndarray]:
+        """Materialize the dispatch's outputs host-side: the host
+        blocks on the device here (on a submesh, on the sharded
+        computation and the cross-shard result assembly)."""
+        with host_span(self._span["fetch"]):
+            return [np.asarray(o) for o in outs]
 
     def gather_pages_device(self, page_ids: List[int]) -> Tuple[Any, int]:
         """Demote-side snapshot: ONE bucketed device gather over an
@@ -835,14 +829,11 @@ class Engine:
             # of the restored KV (head shard when the pool is
             # head-sharded; replicated payload otherwise, with the
             # scatter's index arithmetic routing tokens to the owning
-            # shard) — timed into the shard-DMA series
-            t0 = time.perf_counter()
+            # shard)
             dev = jax.device_put(
                 (np.asarray(pp), np.asarray(ss)),
                 (self._rep_sharding, self._rep_sharding))
             data = jax.device_put(data, self._span_shardings)
-            jax.block_until_ready(data)
-            self.stats["shard_dma_seconds"] += time.perf_counter() - t0
             self.pages = self._scatter_tokens_fn(
                 self.pages, dev[0], dev[1], data)
             return
@@ -1156,7 +1147,12 @@ class Engine:
         cheaper gather). The unfused paged and dense planes serialize
         per-request prefills before the decode batch (reference
         behavior)."""
-        batch = self.scheduler.form_batch(now)
+        with host_span(self._span["step"]):
+            return self._step(now)
+
+    def _step(self, now: float) -> List[Request]:
+        with host_span(self._span["form_batch"]):
+            batch = self.scheduler.form_batch(now)
         if not batch.items and not self.scheduler.prefetch_enabled:
             if self.faults is not None \
                     and self.faults.take_crash(self.econf.instance_id):
@@ -1167,14 +1163,15 @@ class Engine:
         newly_prefilled: List[Request] = []
         if batch.items:
             self.stats["iterations"] += 1
-            aborted = self._admit_new(batch, now)
-            if aborted:
-                batch.items = [it for it in batch.items
-                               if it.request not in aborted]
-            # host-tier restores staged by this step's admissions land
-            # as one batched scatter BEFORE the model reads any lane KV
-            if self._pending_restore:
-                self._flush_restores()
+            with host_span(self._span["admit"]):
+                aborted = self._admit_new(batch, now)
+                if aborted:
+                    batch.items = [it for it in batch.items
+                                   if it.request not in aborted]
+                # host-tier restores staged by this step's admissions land
+                # as one batched scatter BEFORE the model reads any lane KV
+                if self._pending_restore:
+                    self._flush_restores()
 
         # speculative restores issue AFTER admission (no record is in
         # flight while _admit_paged walks tables) and BEFORE the model
@@ -1208,20 +1205,22 @@ class Engine:
                     self._decode_batch_dense(dec)
 
             # -- advance scheduler state --
-            finished = self.scheduler.complete_iteration(batch, now)
-            for r in newly_prefilled:
-                self._store_prefix(r, now)
-            for item in batch.items:
-                r = item.request
-                if item.phase == "decode" and r.output_tokens:
-                    r.output_tokens[-1] = self.live[r.request_id]["next"]
-            for r in finished:
-                lv = self.live.pop(r.request_id, None)
-                self.pool.release(("req", r.request_id) if self.paged
-                                  else r.request_id)
-                if self.draft is not None:
-                    self.draft.release(r.request_id)
-                    self._observe_spec(r, lv, now)
+            with host_span(self._span["finish"]):
+                finished = self.scheduler.complete_iteration(batch, now)
+                for r in newly_prefilled:
+                    self._store_prefix(r, now)
+                for item in batch.items:
+                    r = item.request
+                    if item.phase == "decode" and r.output_tokens:
+                        r.output_tokens[-1] = \
+                            self.live[r.request_id]["next"]
+                for r in finished:
+                    lv = self.live.pop(r.request_id, None)
+                    self.pool.release(("req", r.request_id)
+                                      if self.paged else r.request_id)
+                    if self.draft is not None:
+                        self.draft.release(r.request_id)
+                        self._observe_spec(r, lv, now)
         # land this step's speculative restores (the publish runs after
         # _store_prefix so a same-step split cancels cleanly first),
         # then any demote DMA — both gathers/scatters were dispatched
@@ -1443,18 +1442,21 @@ class Engine:
         # state and page tables never live on device
         wb = ModelWorkerBatch(ctoks, cstart, clen, cpt, dtoks, dpos, dpt)
         fb = self._lower_batch(wb)
+        with host_span(self._span["dispatch"]):
+            if spec_lanes:
+                nxt, cpred, self.pages = self._mixed_spec_fn(
+                    self.params, self.pages, fb.chunk_tokens,
+                    fb.chunk_start, fb.chunk_len, fb.chunk_page_table,
+                    fb.dec_tokens, fb.dec_pos, fb.dec_page_table)
+            else:
+                nxt, self.pages = self._mixed_paged_fn(
+                    self.params, self.pages, fb.chunk_tokens,
+                    fb.chunk_start, fb.chunk_len, fb.chunk_page_table,
+                    fb.dec_tokens, fb.dec_pos, fb.dec_page_table)
         if spec_lanes:
-            nxt, cpred, self.pages = self._mixed_spec_fn(
-                self.params, self.pages, fb.chunk_tokens, fb.chunk_start,
-                fb.chunk_len, fb.chunk_page_table, fb.dec_tokens,
-                fb.dec_pos, fb.dec_page_table)
-            cpred = np.asarray(cpred)
+            nxt, cpred = self._fetch_result(nxt, cpred)
         else:
-            nxt, self.pages = self._mixed_paged_fn(
-                self.params, self.pages, fb.chunk_tokens, fb.chunk_start,
-                fb.chunk_len, fb.chunk_page_table, fb.dec_tokens,
-                fb.dec_pos, fb.dec_page_table)
-        nxt = self._fetch_result(nxt)
+            nxt, = self._fetch_result(nxt)
         self.stats["model_dispatches"] += 1
         self.stats["fused_iterations"] += 1
         self.stats["fused_padded_tokens"] += (
@@ -1540,12 +1542,15 @@ class Engine:
                               np.zeros((0, self._pages_per_req), np.int32),
                               tokens, pos, pt)
         fb = self._lower_batch(wb)
-        nxt, self.pages = self._decode_paged_fn(
-            self.params, self.pages, fb.dec_tokens, fb.dec_pos,
-            fb.dec_page_table)
-        nxt = self._fetch_result(nxt)
+        with host_span(self._span["dispatch"]):
+            nxt, self.pages = self._decode_paged_fn(
+                self.params, self.pages, fb.dec_tokens, fb.dec_pos,
+                fb.dec_page_table)
+        nxt, = self._fetch_result(nxt)
         for i, r in enumerate(dec):
             self.live[r.request_id]["next"] = int(nxt[i])
+        self.stats["decode_only_lanes"] += Bb
+        self.stats["decode_only_padded_lanes"] += Bb - B
         self.stats["decode_steps"] += B
         self.stats["decode_batches"] += 1
         self.stats["model_dispatches"] += 1

@@ -53,7 +53,6 @@ against the dense oracle.
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -307,18 +306,13 @@ class PagedHostTier:
         pending, self._pending = self._pending, []
         eng = self.engine
         ps = eng.pool.page_size
-        sharded = getattr(eng, "mesh", None) is not None
         for rec in pending:
             # device->host landing of the demote gather. On an SPMD
             # submesh each chip ships only its own KV slice (head/slot
             # shard) over its own host link — the copy here assembles
-            # the per-shard pieces, timed into the engine's shard-DMA
-            # series (single-chip engines stay untimed, byte-identical)
-            t0 = time.perf_counter() if sharded else 0.0
+            # the per-shard pieces
             arr = _tree_map(lambda a: np.asarray(a)[:rec["n"]],
                             rec["gathered"])
-            if sharded:
-                eng.stats["shard_dma_seconds"] += time.perf_counter() - t0
             demoted = 0
             for key, node_id, start, cov, ofs, npg in rec["jobs"]:
                 if key in rec["cancelled"]:

@@ -1,7 +1,8 @@
 """Unified telemetry plane (DESIGN.md §12): metrics registry,
 per-request trace timelines, and TTFT/latency attribution.
 
-Three layers, all dependency-free (stdlib only):
+Three layers, all dependency-free (stdlib only; ``host_span`` imports
+jax when called):
 
   * ``MetricsRegistry`` — typed counters / gauges / fixed-bucket
     histograms under ONE shared name vocabulary. Component ``stats``
@@ -21,6 +22,11 @@ Three layers, all dependency-free (stdlib only):
     assert against (crash / retry / prefetch records / terminal
     failures), emitted with the same vocabulary by the real
     ``ClusterRuntime`` and the ``Simulator``.
+
+These stamp the caller's ``now``, which is virtual time in simulated
+runs. Wall-clock work is seen through ``host_span`` instead: named
+spans (``HOST_SPANS``) on the profiler's own clock, written only while
+a ``jax.profiler`` trace is active, beside the device's events.
 
 Gating mirrors the ``faults`` pattern (§11): built with
 ``telemetry=None`` (or ``Telemetry(enabled=False)``) every hook is
@@ -63,18 +69,42 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
            "StatsDict", "RequestTrace", "Telemetry", "request_breakdown",
-           "BREAKDOWN_COMPONENTS", "DEFAULT_TIME_BUCKETS"]
+           "BREAKDOWN_COMPONENTS", "DEFAULT_TIME_BUCKETS", "HOST_SPANS",
+           "host_span"]
 
 # Non-overlapping latency components, in timeline order. Their sum is
 # exactly `latency()`; the first five sum to `ttft()`.
 BREAKDOWN_COMPONENTS = ("sched_delay", "queue", "restore", "migrate",
                         "compute", "decode")
 
+# Every host span the program opens on the profiler's clock
+# (``host_span``). Engine spans carry their instance id as a suffix
+# (``preble.engine.step.0``), built once per engine, so a profile
+# reader needs only the event name: ``step`` is a whole
+# ``Engine.step``; ``form_batch``, ``admit`` (admission plus the
+# restore flush), ``lower``, ``dispatch`` (the jitted step call up to
+# its return), ``fetch`` (the host blocked on the step's result) and
+# ``finish`` (scheduler bookkeeping, prefix publish, releases) lie
+# inside it. ``preble.e2.schedule`` is one ``GlobalScheduler.schedule``.
+HOST_SPANS = ("preble.engine.step", "preble.engine.form_batch",
+              "preble.engine.admit", "preble.engine.lower",
+              "preble.engine.dispatch", "preble.engine.fetch",
+              "preble.engine.finish", "preble.e2.schedule")
+
 # Prometheus-style cumulative upper bounds for request-time histograms
 # (seconds). The final +Inf bucket is implicit.
 DEFAULT_TIME_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
                         0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0,
                         120.0, 300.0)
+
+
+def host_span(name: str, **meta):
+    """Context manager recording ``name`` as a host span of an active
+    profiler trace (``jax.profiler.TraceAnnotation``), on the clock of
+    the device trace. With no trace active it costs about a
+    microsecond and ``meta`` is never encoded."""
+    from jax.profiler import TraceAnnotation    # jax only where spans open
+    return TraceAnnotation(name, **meta)
 
 
 # ---- metric types -----------------------------------------------------------

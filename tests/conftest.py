@@ -33,3 +33,34 @@ def _bound_xla_state_per_module():
     yield
     jax.clear_caches()
     gc.collect()
+
+
+class _Profile:
+    """Profiles a block with the JAX profiler into a directory of its
+    own and reads back the program's host spans."""
+
+    def __init__(self, path):
+        self.path = path
+
+    def trace(self):
+        return jax.profiler.trace(str(self.path))
+
+    def spans(self, prefix="preble."):
+        """(name, start_ns, end_ns) of every host event whose name
+        starts with ``prefix``, in start order."""
+        from jax.profiler import ProfileData
+        out = []
+        for f in self.path.rglob("*.xplane.pb"):
+            for plane in ProfileData.from_file(str(f)).planes:
+                if not plane.name.startswith("/host:"):
+                    continue
+                for line in plane.lines:
+                    out += [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                            for e in line.events
+                            if e.name.startswith(prefix)]
+        return sorted(out, key=lambda s: s[1])
+
+
+@pytest.fixture
+def profile(tmp_path):
+    return _Profile(tmp_path / "profile")
