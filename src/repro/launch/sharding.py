@@ -232,47 +232,48 @@ def cache_shardings(cache_specs: Pytree, mesh: Mesh) -> Pytree:
 # ---------------------------------------------------------------------
 
 def pool_pspec(shape: Tuple[int, ...], mesh: Mesh) -> P:
-    """PartitionSpec for one paged-pool leaf [n_pages, PS, KH, D].
+    """PartitionSpec for one paged-pool leaf [n_pages, KH, R, 128]
+    (``models.attention.pool_leaf_shape``).
 
     Preference order, each guarded by divisibility:
-      1. head-wise   P(None, None, "model", None) — each chip owns
+      1. head-wise   P(None, "model", None, None) — each chip owns
          KH/tp kv-heads of every page (Megatron attention, zero
          resharding inside the kernel);
-      2. slot-wise   P(None, "model", None, None) — the GQA fallback:
+      2. row-wise    P(None, None, "model", None) — the GQA fallback:
          when the TP degree exceeds (or doesn't divide) kv_heads, heads
-         REPLICATE and each chip owns PS/tp token slots of every page
-         (sequence sharding at page granularity — distributed
-         flash-decoding over the slot shards);
+         REPLICATE and each chip owns R/tp rows, i.e. PS/tp token slots,
+         of every page (sequence sharding at page granularity —
+         distributed flash-decoding over the slot shards);
       3. page-wise   P("model", None, None, None) — last resort when
-         the page size doesn't divide either;
+         the rows don't divide either;
       4. replicate.
 
     The guard in step 1 is the serve-time GQA edge: producing an
     indivisible head spec (e.g. KH=1 pools on a 4-chip submesh) would
     be a mesh compile error, so heads replicate and the sequence axis
     takes the shard instead."""
-    n_pages, ps, kh, _d = shape
+    n_pages, kh, rows, _lanes = shape
     if TP_AXIS not in mesh.shape:
         return P(None, None, None, None)
     tp = axis_size(mesh, TP_AXIS)
     if tp <= 1:
         return P(None, None, None, None)
     if kh % tp == 0:
-        return P(None, None, TP_AXIS, None)
-    if ps % tp == 0:
         return P(None, TP_AXIS, None, None)
+    if rows % tp == 0:
+        return P(None, None, TP_AXIS, None)
     if n_pages % tp == 0:
         return P(TP_AXIS, None, None, None)
     return P(None, None, None, None)
 
 
 def span_pspec(shape: Tuple[int, ...], mesh: Mesh) -> P:
-    """PartitionSpec for a token-granular KV span [L, KH, D] — the
-    host<->device DMA payloads (demote gathers land as [nb, PS, KH, D],
-    restore/prefetch scatters ship [L, KH, D]). Only the head shard
-    carries over from ``pool_pspec``: each chip moves exactly its own
-    kv-head slice (per-shard DMA); slot/page-sharded pools replicate
-    the span and let the scatter's index arithmetic route tokens."""
+    """PartitionSpec for token-major KV with heads at axis -2 — the
+    host<->device DMA payloads (restore/prefetch scatters ship pages
+    laid out token-major, [N, PS, KH, D]). Only the head shard carries
+    over from ``pool_pspec``: each chip moves exactly its own kv-head
+    slice (per-shard DMA); row/page-sharded pools replicate the payload
+    and let the scatter route rows to their shards."""
     kh = shape[-2]
     if TP_AXIS not in mesh.shape:
         return P(*([None] * len(shape)))
@@ -284,17 +285,19 @@ def span_pspec(shape: Tuple[int, ...], mesh: Mesh) -> P:
 
 def pool_shardings(pool_specs: Pytree, mesh: Mesh) -> Pytree:
     """NamedShardings for the engine's paged pool pytree
-    ({pj: {gg: {"k"/"v": [n_pages, PS, KH, D]}}})."""
+    ({pj: {gg: {"k"/"v": [n_pages, KH, R, 128]}}})."""
     return jax.tree.map(
         lambda s: NamedSharding(mesh, pool_pspec(s.shape, mesh)),
         pool_specs)
 
 
 def span_shardings(pool_specs: Pytree, mesh: Mesh) -> Pytree:
-    """NamedShardings for token-granular DMA payloads matching the
-    pool tree: leaf [L, KH, D] per pool leaf."""
+    """NamedShardings for the restore payloads matching the pool tree:
+    leaf [N, PS, KH, D] per pool leaf [n_pages, KH, R, 128]; only the
+    pool's head count (axis 1) decides the spec."""
     return jax.tree.map(
-        lambda s: NamedSharding(mesh, span_pspec((1,) + s.shape[2:], mesh)),
+        lambda s: NamedSharding(mesh, span_pspec((1, 1, s.shape[1], 1),
+                                                 mesh)),
         pool_specs)
 
 

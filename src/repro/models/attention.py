@@ -216,6 +216,99 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window: int = 0):
     return out.reshape(B, H, D)
 
 
+# ---------------------------------------------------------------------
+# page-pool layout (DESIGN.md §2)
+# ---------------------------------------------------------------------
+
+LANES = 128     # the TPU's vector width: the minor dimension of a pool leaf
+
+
+def pool_rows(page_size: int, head_dim: int) -> int:
+    """Rows of LANES values that one (page, kv-head) block spans: its
+    page_size x head_dim values, token-major, LANES to a row."""
+    if LANES % head_dim and head_dim % LANES:
+        raise ValueError(f"head_dim {head_dim} neither divides nor is a "
+                         f"multiple of {LANES}")
+    if page_size * head_dim % LANES:
+        raise ValueError(f"a page of {page_size} tokens x {head_dim} does "
+                         f"not fill whole rows of {LANES}")
+    return page_size * head_dim // LANES
+
+
+def pool_leaf_shape(n_pages: int, page_size: int, kv_heads: int,
+                    head_dim: int) -> Tuple[int, int, int, int]:
+    """Shape of one pool leaf: [n_pages, KH, R, LANES] with R =
+    page_size * head_dim / LANES. Token t of a page starts at row
+    (t * D) // LANES, lane (t * D) % LANES of its head's block: D = 64
+    packs two tokens a row, D = 128 is plain [n_pages, KH, PS, D]."""
+    return (n_pages, kv_heads, pool_rows(page_size, head_dim), LANES)
+
+
+def pages_to_tokens(pages, head_dim: int):
+    """Pool pages [..., N, KH, R, LANES] -> token-major [..., N * PS,
+    KH, D] (jax or numpy arrays)."""
+    *lead, n, kh, rows, lanes = pages.shape
+    ps = rows * lanes // head_dim
+    x = pages.reshape(*lead, n, kh, ps, head_dim).swapaxes(-3, -2)
+    return x.reshape(*lead, n * ps, kh, head_dim)
+
+
+def tokens_to_pages(tokens, page_size: int):
+    """Token-major [..., N * PS, KH, D] -> pool pages [..., N, KH, R,
+    LANES]: the inverse of ``pages_to_tokens``."""
+    *lead, t, kh, d = tokens.shape
+    n = t // page_size
+    x = tokens.reshape(*lead, n, page_size, kh, d).swapaxes(-3, -2)
+    return x.reshape(*lead, n, kh, pool_rows(page_size, d), LANES)
+
+
+def gather_tokens(leaf, table, head_dim: int):
+    """The pages of page-table rows ``table`` [B, P], token-major
+    [B, P * PS, KH, D]: reads those pages only, never the pool."""
+    return pages_to_tokens(leaf[table], head_dim)
+
+
+def write_pages(leaf, pids, mask, vals):
+    """Write token KV ``vals`` [N, PS, KH, D] into pool pages ``pids``
+    [N] where ``mask`` [N, PS] is set, keeping the rest of each page:
+    one gather of the N pages, a select, and one scatter of whole
+    [KH, R, LANES] blocks, which XLA applies in place to a donated leaf
+    (a token-granular scatter into the packed rows would not be). Pages
+    in ``pids`` must be distinct, except the scratch page, whose content
+    is garbage."""
+    ps, d = vals.shape[1], vals.shape[-1]
+    old = pages_to_tokens(leaf[pids], d).reshape(vals.shape)
+    new = jnp.where(mask[..., None, None], vals.astype(leaf.dtype), old)
+    return leaf.at[pids].set(tokens_to_pages(new.reshape(-1, *new.shape[2:]),
+                                             ps))
+
+
+def write_runs(leaf, table, start, length, vals):
+    """Write runs of tokens through page tables: ``vals`` [B, C, KH, D]
+    holds lane b's tokens at positions start[b] + t of table row
+    ``table`` [B, P], for t < length[b] (later tokens are padding and
+    write nothing). Each lane touches the W pages its run can span;
+    a window page the run does not reach is sent to the scratch page
+    0, so padding lanes (length 0, all-scratch rows) write only there."""
+    B, C = vals.shape[:2]
+    ps = leaf.shape[2] * LANES // vals.shape[-1]         # tokens a page
+    W = -(-(C + ps - 1) // ps)
+    start = jnp.asarray(start, jnp.int32).reshape(B)
+    length = jnp.asarray(length, jnp.int32).reshape(B)
+    first = start // ps
+    t = first[:, None] * ps + jnp.arange(W * ps)[None, :] - start[:, None]
+    mask = (t >= 0) & (t < length[:, None])                     # [B, W*PS]
+    x = jnp.take_along_axis(vals, jnp.clip(t, 0, C - 1)[..., None, None],
+                            axis=1)
+    wp = jnp.clip(first[:, None] + jnp.arange(W)[None, :], 0,
+                  table.shape[1] - 1)
+    mask = mask.reshape(B * W, ps)
+    pids = jnp.where(mask.any(-1), jnp.take_along_axis(table, wp, axis=1)
+                     .reshape(-1), 0)
+    return write_pages(leaf, pids, mask,
+                       x.reshape(B * W, ps, *vals.shape[2:]))
+
+
 def auto_paged_impl() -> str:
     """The paged-decode implementation 'auto' resolves to: the Pallas
     kernel on a TPU backend, the jnp gather elsewhere."""
@@ -226,27 +319,26 @@ def paged_attention(q, k_pages, v_pages, page_table, lens, *,
                     impl: str = "auto"):
     """Decode attention over a shared KV page pool.
 
-    q: [B, H, D]; k/v_pages: [n_pages, page_size, KH, D] (the pool —
-    shared across every request on the instance); page_table: [B, P]
-    int32 page ids (entries past a request's length may point anywhere,
-    they are masked); lens: [B] valid token counts. Returns [B, H, D].
+    q: [B, H, D]; k/v_pages: pool leaves [n_pages, KH, R, LANES]
+    (``pool_leaf_shape`` — the pool is shared across every request on
+    the instance); page_table: [B, P] int32 page ids (entries past a
+    request's length may point anywhere, they are masked); lens: [B]
+    valid token counts. Returns [B, H, D].
 
     'pallas' streams pages HBM->VMEM via the page-table-prefetched
-    kernel (kernels/paged_attention.py); 'gather' is the jnp reference —
-    a per-request gather of the table rows followed by masked dense
-    decode attention. 'auto' picks pallas on TPU, gather elsewhere
-    (interpret-mode pallas unrolls the page grid and is far slower than
-    one fused gather+softmax on CPU).
+    kernel (kernels/paged_attention.py), reading the leaves as stored;
+    'gather' is the jnp reference — a per-request gather of the table
+    rows followed by masked dense decode attention. 'auto' picks pallas
+    on TPU, gather elsewhere (interpret-mode pallas unrolls the page
+    grid and is far slower than one fused gather+softmax on CPU).
     """
     if impl == "auto":
         impl = auto_paged_impl()
     if impl == "pallas":
         return _paged_kernel(q, k_pages, v_pages, page_table, lens)
-    B, H, D = q.shape
-    _, PS, KH, _ = k_pages.shape
-    P = page_table.shape[1]
-    k = k_pages[page_table].reshape(B, P * PS, KH, D)
-    v = v_pages[page_table].reshape(B, P * PS, KH, D)
+    D = q.shape[-1]
+    k = gather_tokens(k_pages, page_table, D)
+    v = gather_tokens(v_pages, page_table, D)
     return decode_attention(q, k, v, lens)
 
 
@@ -256,7 +348,7 @@ def _paged_kernel(q, k_pages, v_pages, page_table, lens):
     in context with ``sharding.mesh_scope``) it runs per head shard
     under shard_map: heads are independent, and the pool is
     head-sharded whenever the TP degree divides the KV heads. A
-    slot-sharded pool (TP degree above the KV heads) takes the gather
+    row-sharded pool (TP degree above the KV heads) takes the gather
     path, which GSPMD partitions."""
     from ..kernels.paged_attention import paged_decode_attention
     from ..launch.sharding import TP_AXIS, ambient_mesh
@@ -265,10 +357,10 @@ def _paged_kernel(q, k_pages, v_pages, page_table, lens):
           else mesh.shape[TP_AXIS])
     if tp == 1:
         return paged_decode_attention(q, k_pages, v_pages, page_table, lens)
-    if k_pages.shape[2] % tp:
+    if k_pages.shape[1] % tp:
         return paged_attention(q, k_pages, v_pages, page_table, lens,
                                impl="gather")
-    heads, pool = P(None, TP_AXIS, None), P(None, None, TP_AXIS, None)
+    heads, pool = P(None, TP_AXIS, None), P(None, TP_AXIS, None, None)
     return jax.shard_map(paged_decode_attention, mesh=mesh,
                          in_specs=(heads, pool, pool, P(), P()),
                          out_specs=heads, check_vma=False)(
@@ -303,11 +395,9 @@ def mixed_paged_attention(qc, qd, k_pages, v_pages, chunk_table,
 
     Returns (oc [Lc, C, H, D], od [Ld, H, D]).
     """
-    _, PS, KH, D = k_pages.shape
-    Lc, P = chunk_table.shape
-    C = qc.shape[1]
-    kc = k_pages[chunk_table].reshape(Lc, P * PS, KH, D)
-    vc = v_pages[chunk_table].reshape(Lc, P * PS, KH, D)
+    C, D = qc.shape[1], qc.shape[-1]
+    kc = gather_tokens(k_pages, chunk_table, D)
+    vc = gather_tokens(v_pages, chunk_table, D)
     # kv_len = start + C is safe for padded lanes/tokens: the causal
     # mask (k_pos <= q_pos) already bounds every REAL query, and padded
     # queries' outputs are dropped.

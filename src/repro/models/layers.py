@@ -24,7 +24,8 @@ import jax
 import jax.numpy as jnp
 
 from .attention import (attention, decode_attention, extend_attention,
-                        mixed_paged_attention, paged_attention)
+                        gather_tokens, mixed_paged_attention,
+                        paged_attention, write_runs)
 from .common import (constrain_batch, constrain_moe_dispatch, rms_norm,
                      rope)
 from .spec import Spec
@@ -145,16 +146,16 @@ def attn_step(p, cfg, x, cache, pos, *, window: int = 0):
 
 def attn_step_paged(p, cfg, x, pages, page_table, pos):
     """Decode one token against the shared page pool (no per-request
-    cache buffer). x: [B, d]; pages {"k","v"}: [n_pages, PS, KH, D] —
-    the POOL, shared by every request on the instance; page_table:
-    [B, P] page ids; pos: [B] context length so far (the fed token's
-    absolute position). The new KV is scattered into page
-    page_table[b, pos//PS] at offset pos % PS; the pool rows written
-    by different batch lanes are guaranteed distinct by the host-side
+    cache buffer). x: [B, d]; pages {"k","v"}: leaves [n_pages, KH, R,
+    LANES] (``attention.pool_leaf_shape``) — the POOL, shared by every
+    request on the instance; page_table: [B, P] page ids; pos: [B]
+    context length so far (the fed token's absolute position). The new
+    KV is written in place into page page_table[b, pos//PS] at token
+    offset pos % PS (``attention.write_runs``); the pages written by
+    different batch lanes are guaranteed distinct by the host-side
     allocator (shared pages are CoW'd before a sequence may write).
     Returns (y, new pages)."""
     B, d = x.shape
-    PS = pages["k"].shape[1]
     q = jnp.einsum("bd,dhk->bhk", x, p["wq"])
     k = jnp.einsum("bd,dhk->bhk", x, p["wk"])
     v = jnp.einsum("bd,dhk->bhk", x, p["wv"])
@@ -164,11 +165,9 @@ def attn_step_paged(p, cfg, x, pages, page_table, pos):
     if cfg.rope_theta:
         q = rope(q[:, None], pos[:, None], cfg.rope_theta)[:, 0]
         k = rope(k[:, None], pos[:, None], cfg.rope_theta)[:, 0]
-    bidx = jnp.arange(B)
-    pids = page_table[bidx, pos // PS]
-    offs = pos % PS
-    k_pages = pages["k"].at[pids, offs].set(k.astype(pages["k"].dtype))
-    v_pages = pages["v"].at[pids, offs].set(v.astype(pages["v"].dtype))
+    one = jnp.ones_like(pos)
+    k_pages = write_runs(pages["k"], page_table, pos, one, k[:, None])
+    v_pages = write_runs(pages["v"], page_table, pos, one, v[:, None])
     o = paged_attention(q, k_pages, v_pages, page_table, pos + 1)
     y = jnp.einsum("bhk,hkd->bd", o, p["wo"])
     return y, {"k": k_pages, "v": v_pages}
@@ -185,14 +184,13 @@ def attn_mixed_paged(p, cfg, xc, xd, pages, chunk_table, chunk_start,
     attends, then attention runs per half (extend-style for chunks,
     decode-style for single-token lanes — mixed_paged_attention).
 
-    Chunk-pad tokens (beyond chunk_len) are redirected to the scratch
-    page: a full table row's clip-clamped tail entry would otherwise
-    point garbage writes at the lane's own live pages. Padding LANES
-    must carry all-scratch table rows with start/pos 0.
+    Chunk-pad tokens (beyond chunk_len) write nothing, and window pages
+    a chunk does not reach go to the scratch page (``write_runs``): a
+    full table row's clip-clamped tail entry would otherwise point
+    garbage writes at the lane's own live pages. Padding LANES must
+    carry all-scratch table rows with start/pos 0.
     Returns (yc [Lc, C, d], yd [Ld, d], new pages)."""
-    Lc, C, _ = xc.shape
-    Ld = xd.shape[0]
-    PS = pages["k"].shape[1]
+    C = xc.shape[1]
     qc, kc, vc = _project_qkv(p, cfg, xc)
     qd = jnp.einsum("bd,dhk->bhk", xd, p["wq"])
     kd = jnp.einsum("bd,dhk->bhk", xd, p["wk"])
@@ -203,16 +201,13 @@ def attn_mixed_paged(p, cfg, xc, xd, pages, chunk_table, chunk_start,
         kc = rope(kc, cpos, cfg.rope_theta)
         qd = rope(qd[:, None], dec_pos[:, None], cfg.rope_theta)[:, 0]
         kd = rope(kd[:, None], dec_pos[:, None], cfg.rope_theta)[:, 0]
-    valid = jnp.arange(C)[None, :] < chunk_len[:, None]
-    lidx = jnp.arange(Lc)[:, None]
-    cpids = jnp.where(valid, chunk_table[lidx, cpos // PS], 0)
-    coffs = jnp.where(valid, cpos % PS, 0)
-    dpids = dec_table[jnp.arange(Ld), dec_pos // PS]
-    doffs = dec_pos % PS
-    k_pages = pages["k"].at[cpids, coffs].set(kc.astype(pages["k"].dtype))
-    v_pages = pages["v"].at[cpids, coffs].set(vc.astype(pages["v"].dtype))
-    k_pages = k_pages.at[dpids, doffs].set(kd.astype(k_pages.dtype))
-    v_pages = v_pages.at[dpids, doffs].set(vd.astype(v_pages.dtype))
+    one = jnp.ones_like(dec_pos)
+    k_pages = write_runs(pages["k"], chunk_table, chunk_start, chunk_len,
+                         kc)
+    v_pages = write_runs(pages["v"], chunk_table, chunk_start, chunk_len,
+                         vc)
+    k_pages = write_runs(k_pages, dec_table, dec_pos, one, kd[:, None])
+    v_pages = write_runs(v_pages, dec_table, dec_pos, one, vd[:, None])
     oc, od = mixed_paged_attention(qc, qd, k_pages, v_pages, chunk_table,
                                    chunk_start, dec_table, dec_pos)
     yc = jnp.einsum("...hk,hkd->...d", oc, p["wo"])
@@ -226,8 +221,6 @@ def attn_extend_paged(p, cfg, x, pages, page_table, start):
     KV is scattered into the table's pages, then chunk queries attend
     to the gathered table rows. Returns (y [B, C, d], new pages)."""
     B, C, d = x.shape
-    PS = pages["k"].shape[1]
-    P = page_table.shape[1]
     q, k, v = _project_qkv(p, cfg, x)
     start = jnp.asarray(start)
     positions = jnp.broadcast_to(
@@ -236,14 +229,11 @@ def attn_extend_paged(p, cfg, x, pages, page_table, start):
     if cfg.rope_theta:
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
-    bidx = jnp.arange(B)[:, None]
-    pids = page_table[bidx, positions // PS]                 # [B, C]
-    offs = positions % PS
-    k_pages = pages["k"].at[pids, offs].set(k.astype(pages["k"].dtype))
-    v_pages = pages["v"].at[pids, offs].set(v.astype(pages["v"].dtype))
-    KH, D = k_pages.shape[2], k_pages.shape[3]
-    kc = k_pages[page_table].reshape(B, P * PS, KH, D)
-    vc = v_pages[page_table].reshape(B, P * PS, KH, D)
+    full = jnp.full((B,), C, jnp.int32)
+    k_pages = write_runs(pages["k"], page_table, positions[:, 0], full, k)
+    v_pages = write_runs(pages["v"], page_table, positions[:, 0], full, v)
+    kc = gather_tokens(k_pages, page_table, cfg.head_dim)
+    vc = gather_tokens(v_pages, page_table, cfg.head_dim)
     o = extend_attention(q, kc, vc, start, start + C)
     y = jnp.einsum("...hk,hkd->...d", o, p["wo"])
     return y, {"k": k_pages, "v": v_pages}

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from . import layers as L
 from . import mamba as M
 from . import rwkv6 as R
+from .attention import pool_leaf_shape
 from .common import constrain_batch, rms_norm
 from .spec import Spec
 
@@ -307,9 +308,10 @@ def forward_step_paged(params, cfg, x, pages, page_table, position
                        ) -> Tuple[jax.Array, Pytree]:
     """x: [B, d] one embedded token per batch lane; ``pages`` is the
     instance-wide KV page pool {"p{j}": {"g{g}": {"k","v": [n_pages,
-    PS, KH, D]}}} (caller donates the buffers); ``page_table``: [B, P]
-    page ids; ``position``: [B] int32 context lengths. Returns (hidden,
-    updated pool). Attention-only stacks — see paged_cache_specs.
+    KH, R, 128]}}} (``paged_cache_specs``; caller donates the buffers);
+    ``page_table``: [B, P] page ids; ``position``: [B] int32 context
+    lengths. Returns (hidden, updated pool). Attention-only stacks —
+    see paged_cache_specs.
 
     Unlike the dense step, the layer loop is UNROLLED rather than
     scanned: scanning over the pool would slice each group's pages in
@@ -567,21 +569,24 @@ def paged_servable(cfg) -> bool:
 
 
 def paged_cache_specs(cfg, n_pages: int, page_size: int) -> Pytree:
-    """ShapeDtypeStructs of the per-layer KV page pools: one
-    [n_pages, page_size, KH, D] k/v pair per (attention position,
-    scan group) — i.e. per physical layer. The pool is instance-wide:
-    requests address it through page tables, so there is no batch or
-    seq dim, and leaves are kept per-layer (not stacked over groups)
-    so the unrolled paged forwards update them in place. On a serve
-    submesh each leaf shards by ``launch.sharding.pool_pspec`` (heads
-    when divisible, else page slots), so every chip holds a 1/tp slice
-    of EVERY page — aggregate pool capacity scales with the submesh."""
+    """ShapeDtypeStructs of the per-layer KV page pools: one k/v pair
+    per (attention position, scan group) — i.e. per physical layer —
+    each leaf [n_pages, KH, R, 128] in the paged kernel's block layout
+    (``attention.pool_leaf_shape``, DESIGN.md §2). The pool is
+    instance-wide: requests address it through page tables, so there
+    is no batch or seq dim, and leaves are kept per-layer (not stacked
+    over groups) so the unrolled paged forwards update them in place.
+    On a serve submesh each leaf shards by ``launch.sharding.pool_pspec``
+    (heads when divisible, else a page's rows), so every chip holds a
+    1/tp slice of EVERY page — aggregate pool capacity scales with the
+    submesh."""
     if not paged_servable(cfg):
         raise ValueError(f"{cfg.name}: stack is not paged-servable")
     plan = layer_plan(cfg)
     dt = jnp.dtype(cfg.dtype)
     s = jax.ShapeDtypeStruct(
-        (n_pages, page_size, cfg.n_kv_heads, cfg.head_dim), dt)
+        pool_leaf_shape(n_pages, page_size, cfg.n_kv_heads, cfg.head_dim),
+        dt)
     return {f"p{j}": {f"g{g}": {"k": s, "v": s}
                       for g in range(cfg.n_groups)}
             for j, _pos in enumerate(plan)}

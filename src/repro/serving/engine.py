@@ -8,7 +8,8 @@ LRU eviction).
 Two data planes share the scheduling logic (DESIGN.md §2):
 
   * PAGED (default for attention-only stacks) — all KV lives in one
-    device-resident page pool per layer ([n_pages, page_size, KH, D]);
+    device-resident page pool per layer ([n_pages, KH, R, 128], the
+    paged kernel's block layout: models/attention.pool_leaf_shape);
     requests address it through page tables held by
     serving/kv_cache.py::PagedKVPool. Prefix reuse is ``fork()`` page
     aliasing with refcounts + copy-on-write — admission performs ZERO
@@ -70,6 +71,7 @@ from ..core.request import Request, RequestState
 from ..launch import sharding as shard_lib
 from ..launch.mesh import make_serve_mesh
 from ..models import zoo, transformer as T
+from ..models.attention import write_pages
 from .batch import ForwardBatch, ModelWorkerBatch
 from .faults import CircuitBreaker, InstanceCrashed
 from .kv_cache import PagedKVPool
@@ -461,15 +463,16 @@ class Engine:
                  "dec_page_table": dec_pt})
 
     def _copy_page_impl(self, pages, src, dst):
-        # pool leaves are [n_pages, PS, KH, D] (per layer; see
-        # transformer.paged_cache_specs)
+        # a page is one [KH, R, 128] block of each pool leaf (per
+        # layer; see transformer.paged_cache_specs)
         return jax.tree.map(lambda a: a.at[dst].set(a[src]), pages)
 
-    def _scatter_tokens_impl(self, pages, pidx, sidx, data):
-        """Token-granular KV scatter (host-tier restore): write
-        data[t] into pages[pidx[t], sidx[t]] for every restored token.
-        Padding tokens carry pidx 0 — the reserved scratch page."""
-        return jax.tree.map(lambda a, d: a.at[pidx, sidx].set(d),
+    def _scatter_tokens_impl(self, pages, pids, mask, data):
+        """KV scatter of the host-tier restore, a page at a time: write
+        data [N, PS, KH, D] into pages ``pids`` [N] at the slots
+        ``mask`` [N, PS] sets (attention.write_pages). Padding pages
+        are the reserved scratch page 0."""
+        return jax.tree.map(lambda a, d: write_pages(a, pids, mask, d),
                             pages, data)
 
     # ---- host/device batch boundary (DESIGN.md §13) ------------------------
@@ -801,44 +804,45 @@ class Engine:
         self.stats["restored_tokens"] += hi - lo
 
     def _scatter_staged(self, staged: List[Tuple]) -> None:
-        """ONE donated, bucketed (page, slot) scatter for a list of
-        staged (pidx, sidx, data) triples — shared by the admission
-        restore flush and the speculative-restore stream so padding
-        (zero indices target the reserved scratch page) and bucketing
-        can never diverge between the two DMA paths."""
+        """ONE donated, bucketed page scatter for a list of staged
+        (pidx, sidx, data) triples — shared by the admission restore
+        flush and the speculative-restore stream so padding (zero page
+        ids target the reserved scratch page) and bucketing can never
+        diverge between the two DMA paths. The tokens are laid out
+        host-side into the pages they land on, with a mask of the slots
+        they fill, so the device writes whole pages."""
         pidx = np.concatenate([s[0] for s in staged])
         sidx = np.concatenate([s[1] for s in staged])
-        n = len(pidx)
+        pids, slot_page = np.unique(pidx, return_inverse=True)
+        n, ps = len(pids), self.pool.page_size
         nb = _bucket(n)
         pp = np.zeros(nb, np.int32)
-        pp[:n] = pidx
-        ss = np.zeros(nb, np.int32)
-        ss[:n] = sidx
+        pp[:n] = pids
+        mask = np.zeros((nb, ps), bool)
+        mask[slot_page, sidx] = True
 
-        def cat(*leaves):
+        def lay_out(*leaves):
             x = (leaves[0] if len(leaves) == 1
                  else np.concatenate(leaves, axis=0))
-            if nb > n:
-                x = np.concatenate(
-                    [x, np.zeros((nb - n,) + x.shape[1:], x.dtype)], axis=0)
-            return x
+            out = np.zeros((nb, ps) + x.shape[1:], x.dtype)
+            out[slot_page, sidx] = x
+            return out
 
-        data = jax.tree.map(cat, *[s[2] for s in staged])
+        data = jax.tree.map(lay_out, *[s[2] for s in staged])
         if self.mesh is not None:
             # per-shard DMA: each chip receives exactly its own slice
             # of the restored KV (head shard when the pool is
             # head-sharded; replicated payload otherwise, with the
-            # scatter's index arithmetic routing tokens to the owning
+            # scatter's index arithmetic routing rows to the owning
             # shard)
             dev = jax.device_put(
-                (np.asarray(pp), np.asarray(ss)),
-                (self._rep_sharding, self._rep_sharding))
+                (pp, mask), (self._rep_sharding, self._rep_sharding))
             data = jax.device_put(data, self._span_shardings)
             self.pages = self._scatter_tokens_fn(
                 self.pages, dev[0], dev[1], data)
             return
         self.pages = self._scatter_tokens_fn(
-            self.pages, jnp.asarray(pp), jnp.asarray(ss),
+            self.pages, jnp.asarray(pp), jnp.asarray(mask),
             jax.tree.map(jnp.asarray, data))
 
     @staticmethod

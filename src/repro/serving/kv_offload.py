@@ -41,9 +41,11 @@ Split of responsibilities:
     endpoints.
 
 Entries are TOKEN-granular (arrays of shape [span, KH, D] per layer
-leaf), so demote/restore/migrate boundaries are independent of page
-alignment; the engine's restore scatter maps tokens back onto
-(page, slot) pairs of the destination request's table.
+leaf, unpacked from the pool's [KH, R, 128] page blocks by
+``models.attention.pages_to_tokens``), so demote/restore/migrate
+boundaries are independent of page alignment; the engine's restore
+scatter maps tokens back onto (page, slot) pairs of the destination
+request's table.
 
 All numpy buffers are C-contiguous host arrays ("pinned" in the TPU
 runtime sense: jax device_get lands them in transfer-friendly memory);
@@ -59,6 +61,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..core.radix_tree import PathKey
+from ..models.attention import pages_to_tokens
 
 Pytree = Any
 
@@ -305,7 +308,7 @@ class PagedHostTier:
         compute — or forced earlier by a read that needs the bytes."""
         pending, self._pending = self._pending, []
         eng = self.engine
-        ps = eng.pool.page_size
+        ps, head_dim = eng.pool.page_size, eng.model_cfg.head_dim
         for rec in pending:
             # device->host landing of the demote gather. On an SPMD
             # submesh each chip ships only its own KV slice (head/slot
@@ -320,7 +323,7 @@ class PagedHostTier:
                 base = (start // ps) * ps
                 span = _tree_map(
                     lambda x: np.ascontiguousarray(
-                        x[ofs:ofs + npg].reshape((npg * ps,) + x.shape[2:])
+                        pages_to_tokens(x[ofs:ofs + npg], head_dim)
                         [start - base:cov - base]),
                     arr)
                 self.store.put(key, start, span, cov - start,

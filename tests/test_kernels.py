@@ -120,14 +120,38 @@ PAGED_CASES = [(3, 8, 2, 16, 4, 32, 64), (2, 4, 4, 8, 6, 24, 32),
                (1, 16, 8, 32, 3, 16, 128)]
 
 
+def paged_pool(key, n_pages, page, KH, D):
+    """Random KV pages token-major [n_pages, page, KH, D] (what the
+    oracles read), and the same values as a pool leaf in the layout the
+    kernel reads (models.attention.tokens_to_pages)."""
+    from repro.models.attention import tokens_to_pages
+    tok = rnd(key, n_pages, page, KH, D)
+    return tok, tokens_to_pages(tok.reshape(n_pages * page, KH, D), page)
+
+
+def dense_oracle(q, k_tok, v_tok, pt, lens):
+    """Decode attention over each lane's table rows, gathered from the
+    token-major pages."""
+    B = q.shape[0]
+    n, page, KH, D = k_tok.shape
+    P = pt.shape[1]
+    dense_k = jnp.stack([k_tok[pt[b]].reshape(page * P, KH, D)
+                         for b in range(B)])
+    dense_v = jnp.stack([v_tok[pt[b]].reshape(page * P, KH, D)
+                         for b in range(B)])
+    return ref.decode_attention_ref(q, dense_k.transpose(0, 2, 1, 3),
+                                    dense_v.transpose(0, 2, 1, 3),
+                                    jnp.asarray(lens))
+
+
 @pytest.mark.parametrize("case", PAGED_CASES)
 def test_paged_decode_attention(case):
     """Page-table-driven decode attention == dense-gathered oracle."""
     from repro.kernels.paged_attention import paged_decode_attention
     B, H, KH, page, P, n_pages, D = case
     ks = jax.random.split(K0, 3)
-    k_pages = rnd(ks[0], n_pages, page, KH, D)
-    v_pages = rnd(ks[1], n_pages, page, KH, D)
+    k_tok, k_pages = paged_pool(ks[0], n_pages, page, KH, D)
+    v_tok, v_pages = paged_pool(ks[1], n_pages, page, KH, D)
     q = rnd(ks[2], B, H, D)
     rng = np.random.default_rng(case[0])
     pt = np.stack([rng.choice(n_pages, P, replace=False)
@@ -135,13 +159,7 @@ def test_paged_decode_attention(case):
     lens = rng.integers(1, page * P + 1, B)
     out = paged_decode_attention(q, k_pages, v_pages, jnp.asarray(pt),
                                  jnp.asarray(lens), interpret=True)
-    dense_k = jnp.stack([k_pages[pt[b]].reshape(page * P, KH, D)
-                         for b in range(B)])
-    dense_v = jnp.stack([v_pages[pt[b]].reshape(page * P, KH, D)
-                         for b in range(B)])
-    exp = ref.decode_attention_ref(q, dense_k.transpose(0, 2, 1, 3),
-                                   dense_v.transpose(0, 2, 1, 3),
-                                   jnp.asarray(lens))
+    exp = dense_oracle(q, k_tok, v_tok, pt, lens)
     np.testing.assert_allclose(out, exp, atol=3e-5, rtol=1e-4)
 
 
@@ -150,39 +168,36 @@ def test_paged_decode_attention(case):
 PAGED_EDGE_LENS = [[3, 16, 21, 64], [1, 8, 48, 63], [16, 32, 5, 17]]
 
 
+@pytest.mark.parametrize("D", [64, 128])
 @pytest.mark.parametrize("lens", PAGED_EDGE_LENS)
-def test_paged_decode_attention_ragged_lens(lens):
+def test_paged_decode_attention_ragged_lens(lens, D):
     """Per-request lengths hitting every page-boundary edge: len <
-    page_size, partial last page, exact page multiple, full table."""
+    page_size, partial last page, exact page multiple, full table; an
+    odd length ends inside a packed row at D = 64."""
     from repro.kernels.paged_attention import paged_decode_attention
-    B, H, KH, page, P, n_pages, D = len(lens), 8, 2, 16, 4, 24, 32
+    B, H, KH, page, P, n_pages = len(lens), 8, 2, 16, 4, 24
     ks = jax.random.split(K0, 3)
-    k_pages = rnd(ks[0], n_pages, page, KH, D)
-    v_pages = rnd(ks[1], n_pages, page, KH, D)
+    k_tok, k_pages = paged_pool(ks[0], n_pages, page, KH, D)
+    v_tok, v_pages = paged_pool(ks[1], n_pages, page, KH, D)
     q = rnd(ks[2], B, H, D)
     rng = np.random.default_rng(7)
     pt = np.stack([rng.choice(n_pages, P, replace=False) for _ in range(B)])
     out = paged_decode_attention(q, k_pages, v_pages, jnp.asarray(pt),
                                  jnp.asarray(lens), interpret=True)
-    dense_k = jnp.stack([k_pages[pt[b]].reshape(page * P, KH, D)
-                         for b in range(B)])
-    dense_v = jnp.stack([v_pages[pt[b]].reshape(page * P, KH, D)
-                         for b in range(B)])
-    exp = ref.decode_attention_ref(q, dense_k.transpose(0, 2, 1, 3),
-                                   dense_v.transpose(0, 2, 1, 3),
-                                   jnp.asarray(lens))
+    exp = dense_oracle(q, k_tok, v_tok, pt, lens)
     np.testing.assert_allclose(out, exp, atol=3e-5, rtol=1e-4)
 
 
-def test_paged_gather_reference_matches_kernel():
+@pytest.mark.parametrize("D", [64, 128])
+def test_paged_gather_reference_matches_kernel(D):
     """models/attention.paged_attention 'gather' impl (the CPU engine
     path) == the Pallas kernel (interpret) on the same inputs."""
     from repro.kernels.paged_attention import paged_decode_attention
     from repro.models.attention import paged_attention
-    B, H, KH, page, P, n_pages, D = 3, 8, 2, 16, 4, 24, 32
+    B, H, KH, page, P, n_pages = 3, 8, 2, 16, 4, 24
     ks = jax.random.split(K0, 3)
-    k_pages = rnd(ks[0], n_pages, page, KH, D)
-    v_pages = rnd(ks[1], n_pages, page, KH, D)
+    _, k_pages = paged_pool(ks[0], n_pages, page, KH, D)
+    _, v_pages = paged_pool(ks[1], n_pages, page, KH, D)
     q = rnd(ks[2], B, H, D)
     rng = np.random.default_rng(11)
     pt = jnp.asarray(np.stack([rng.choice(n_pages, P, replace=False)

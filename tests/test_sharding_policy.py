@@ -184,28 +184,29 @@ def test_serve_policy_big_fsdp_embed_rule():
 # ---------------------------------------------------------------------
 
 def test_pool_pspec_head_wise_when_divisible():
-    # KH=8 divides tp=4 -> Megatron head sharding
-    assert pool_pspec((289, 16, 8, 64), TP4) == P(None, None, "model", None)
+    # leaves are [n_pages, KH, R, 128]; KH=8 divides tp=4 -> Megatron
+    # head sharding
+    assert pool_pspec((289, 8, 8, 128), TP4) == P(None, "model", None, None)
 
 
 def test_pool_pspec_gqa_falls_back_to_slots():
     """The GQA edge: TP degree exceeds kv_heads -> heads must REPLICATE
-    and the page-slot dim takes the shard (an indivisible head spec
-    would be a compile error, not a slow path)."""
-    spec = pool_pspec((289, 16, 1, 64), TP4)
-    assert spec == P(None, "model", None, None)
-    assert spec[2] is None                         # heads replicated
+    and a page's rows (its token slots) take the shard (an indivisible
+    head spec would be a compile error, not a slow path)."""
+    spec = pool_pspec((289, 1, 8, 128), TP4)
+    assert spec == P(None, None, "model", None)
+    assert spec[1] is None                         # heads replicated
     # KH=6 doesn't divide tp=4 either -> same fallback
-    assert pool_pspec((289, 16, 6, 64), TP4) == P(None, "model", None, None)
+    assert pool_pspec((289, 6, 8, 128), TP4) == P(None, None, "model", None)
 
 
 def test_pool_pspec_page_wise_last_resort_and_replicate():
-    # neither heads (1) nor slots (15) divide; pages (288) do
-    assert pool_pspec((288, 15, 1, 64), TP4) == P("model", None, None, None)
+    # neither heads (1) nor rows (15) divide; pages (288) do
+    assert pool_pspec((288, 1, 15, 128), TP4) == P("model", None, None, None)
     # nothing divides -> replicate rather than produce an illegal spec
-    assert pool_pspec((289, 15, 1, 64), TP4) == P(None, None, None, None)
+    assert pool_pspec((289, 1, 15, 128), TP4) == P(None, None, None, None)
     # tp=1 or no "model" axis -> always replicate
-    assert pool_pspec((289, 16, 8, 64), FakeMesh({"data": 4, "model": 1})) \
+    assert pool_pspec((289, 8, 8, 128), FakeMesh({"data": 4, "model": 1})) \
         == P(None, None, None, None)
 
 

@@ -211,6 +211,10 @@ def test_pool_capacity_scales_with_chips(small_model):
     scheduler budget and physical pool alike — scale linearly with the
     submesh, and each chip holds a 1/tp slice of every page."""
     cfg, api, params = small_model
+    # head_dim 64: a 16-token page is 8 rows of 128 lanes, which 2 and
+    # 4 chips divide
+    cfg = dataclasses.replace(cfg, head_dim=64)
+    params = zoo.build(cfg).init(jax.random.PRNGKey(0))
     pools = {}
     for chips in (1, 2, 4):
         ec = _econf(chips, device_capacity=2048 * chips)  # 2048/chip
@@ -221,12 +225,15 @@ def test_pool_capacity_scales_with_chips(small_model):
         pools[chips] = eng.pool.num_pages * ec.page_size
         if chips > 1:
             leaf = jax.tree.leaves(eng.pages)[0]
-            assert leaf.sharding.spec == P(None, "model", None, None), \
+            assert leaf.sharding.spec == P(None, None, "model", None), \
                 "KH=1 pool must slot-shard (GQA fallback)"
             shards = leaf.addressable_shards
             assert len(shards) == chips
-            # slot dim split 1/chips; page count NOT split (pooling)
-            assert shards[0].data.shape[1] == ec.page_size // chips
+            # a page's rows (token slots) split 1/chips; page count NOT
+            # split (pooling)
+            assert shards[0].data.shape[2] == leaf.shape[2] // chips
+            assert shards[0].data.shape[2] * 128 // 64 == \
+                ec.page_size // chips
             assert shards[0].data.shape[0] == leaf.shape[0]
     base = pools[1] - 2 * 16 * 16 - 16   # scratch+headroom pages fixed
     assert pools[2] - pools[1] == 2048
@@ -247,7 +254,7 @@ def test_gqa_head_sharding_when_divisible(small_model):
         eng = Engine(cfg2, params2, _econf(chips))
         if chips > 1:
             leaf = jax.tree.leaves(eng.pages)[0]
-            assert leaf.sharding.spec == P(None, None, "model", None)
+            assert leaf.sharding.spec == P(None, "model", None, None)
         outs[chips] = _outs(_drive(eng, _waves(cfg2, 11)))
     assert outs[2] == outs[1]
 
@@ -278,7 +285,7 @@ def test_kernel_runs_per_head_shard(small_model, monkeypatch):
     monkeypatch.setattr(kernel_mod, "paged_decode_attention", kernel)
     eng = Engine(cfg2, params2, _econf(2))
     assert jax.tree.leaves(eng.pages)[0].sharding.spec == \
-        P(None, None, "model", None)
+        P(None, "model", None, None)
     assert _outs(_drive(eng, _waves(cfg2, 5))) == ref
     assert traced and all(shape[1] == 1 for shape in traced)
 
