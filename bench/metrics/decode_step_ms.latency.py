@@ -1,0 +1,5 @@
+"""``decode_step_ms`` (``bench/metrics/decode_step_ms.py``) in the cells that report
+``latency_p50_s`` and not ``itl_p99_ms``: one quantity, named by the
+end-to-end metric it moves there."""
+
+from bench.metrics.decode_step_ms import read  # noqa: F401

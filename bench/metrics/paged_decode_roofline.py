@@ -1,9 +1,9 @@
 """Kernel: the Pallas paged-decode attention kernel's share of its
 roofline. Least time is, per kernel call (one layer of one step's
 decode lanes), the larger of FLOPs over peak and bytes over HBM
-bandwidth, counted from each lane's valid KV length
-(``bench.work.decode_attention``); it is divided by the kernel's
-device time in the trace.
+bandwidth, counted from the keys each lane's query reads in that layer
+(``bench.work.decode_attention``); summed over layers and steps, it is
+divided by the kernel's device time in the trace.
 
 The kernel is the ``pallas_call`` of ``kernels/paged_attention.py``,
 which has no name of its own: in a TPU trace its op is a custom call
@@ -26,7 +26,7 @@ def read(run):
     calls = [st.dec_lens for st in run.steps if st.dec_lens]
     if t <= 0 or not calls:
         return None
-    least = run.dims.layers * sum(
-        least_time(*decode_attention(run.dims, lens), run.peaks)
-        for lens in calls)
+    least = sum(least_time(*decode_attention(run.dims, layer, lens),
+                           run.peaks)
+                for lens in calls for layer in range(run.dims.layers))
     return 100.0 * least / t

@@ -1,0 +1,5 @@
+"""``step_mfu`` (``bench/metrics/step_mfu.py``) in the cells that report
+``latency_p50_s`` and not ``itl_p99_ms``: one quantity, named by the
+end-to-end metric it moves there."""
+
+from bench.metrics.step_mfu import read  # noqa: F401

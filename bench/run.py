@@ -211,7 +211,7 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool, *,
     from repro.serving.cluster import ClusterRuntime
 
     conf, cp = cell.config, cell.params
-    dims = model.Dims.of(conf["model"])
+    dims = model.dims_of(conf)
     cache_dir = enable_compile_cache()
     compiles = [0]
 

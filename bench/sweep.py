@@ -65,8 +65,8 @@ def main(argv=None) -> int:
                **out["metrics"]}
         rows.append(row)
         print(json.dumps(row), flush=True)
-    keys = ["rate_rps", "ttft_p90_s", "itl_p99_ms", "latency_p90_s",
-            "output_tokens_per_s", "attempted", "failed", "correct"]
+    keys = ["rate_rps", *cell.end_to_end, "attempted", "failed",
+            "correct"]
     print(" | ".join(keys))
     for r in rows:
         print(" | ".join(f"{r.get(k)}" if not isinstance(r.get(k), float)

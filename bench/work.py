@@ -12,6 +12,8 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import List, Sequence, Tuple
 
+import numpy as np
+
 BF16 = 2
 
 
@@ -21,13 +23,15 @@ class Step:
     dec_lens: List[int]               # valid KV length per decode lane
 
 
-def decode_attention(dims, lens: Sequence[int]) -> Tuple[float, float]:
+def decode_attention(dims, layer: int,
+                     lens: Sequence[int]) -> Tuple[float, float]:
     """FLOPs and HBM bytes of one layer's decode attention over lanes
-    of KV length ``lens``: q.K and p.V (2 * 2 * heads * head_dim per
-    key), reading each valid key and value once (bf16) and the query
-    and output once."""
+    of KV length ``lens`` (the query at position length - 1): q.K and
+    p.V (2 * 2 * heads * head_dim per key the query reads there),
+    reading each such key and value once (bf16) and the query and
+    output once."""
     H, KH, D = dims.heads, dims.kv_heads, dims.head_dim
-    n = sum(lens)
+    n = int(np.sum(dims.keys_read(layer, np.asarray(lens, np.int64) - 1)))
     flops = 4.0 * H * D * n
     byts = 2.0 * KH * D * BF16 * n + 2.0 * H * D * BF16 * len(lens)
     return flops, byts
@@ -40,16 +44,20 @@ def least_time(flops: float, byts: float, peaks: dict) -> float:
 
 
 def step_flops(dims, step: Step) -> float:
-    """Model FLOPs of one step's tokens through the layers: the
-    projections and MLP for every token, and attention over each
-    token's true causal context. The head is counted apart
-    (``head_flops``), on emitted tokens only."""
-    L, H, D = dims.layers, dims.heads, dims.head_dim
-    tokens = sum(n for _, n in step.chunks) + len(step.dec_lens)
-    dense = 2.0 * dims.layer_matmul_params() * tokens
-    ctx = sum(n * s + n * (n + 1) / 2 for s, n in step.chunks)
-    ctx += sum(step.dec_lens)
-    return L * (dense + 4.0 * H * D * ctx)
+    """Model FLOPs of one step's tokens through the layers, summed over
+    layers: each layer's matmul weights for every token, and attention
+    over the keys each token's query reads in that layer. The head is
+    counted apart (``head_flops``), on emitted tokens only."""
+    H, D = dims.heads, dims.head_dim
+    pos = np.concatenate([np.arange(s, s + n, dtype=np.int64)
+                          for s, n in step.chunks]
+                         + [np.asarray(step.dec_lens, np.int64) - 1])
+    total = 0
+    for layer in range(dims.layers):
+        keys = int(np.sum(dims.keys_read(layer, pos)))
+        total += (2 * dims.layer_matmul_params(layer) * len(pos)
+                  + 4 * H * D * keys)
+    return float(total)
 
 
 def head_flops(dims, emitted: int) -> float:

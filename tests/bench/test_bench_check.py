@@ -80,7 +80,8 @@ def test_verdict():
 
 @pytest.mark.parametrize("tied", [True, False])
 def test_reference_and_controls_run_tied_and_untied(tied):
-    from bench.model import Dims, make_weights
+    from bench.archs.dense_decoder import Dims
+    from bench.model import make_weights
     from bench.references import dense_decoder
     dims = Dims(layers=2, d=32, heads=4, kv_heads=2, head_dim=8, ff=64,
                 vocab=64, tied=tied, rope_theta=1e4, eps=1e-5)

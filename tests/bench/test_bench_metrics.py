@@ -7,10 +7,10 @@ from types import SimpleNamespace as NS
 import pytest
 
 from bench.harness import Flight
-from bench.metrics import (arrival_lag_p99_ms, itl_p99_ms, latency_p90_s,
+from bench.metrics import (arrival_lag_p99_ms, itl_p99_ms, latency_p50_s,
                            output_tokens_per_s, padded_token_share,
                            prompt_cached_share, queue_wait_p90_s,
-                           ttft_p90_s)
+                           ttft_p50_s)
 from bench.run import Run
 from bench.stats import percentile
 from bench.traffic import Prompt
@@ -44,12 +44,18 @@ def test_latency_tails_count_unfinished_requests_as_censored():
     stuck = _flight(19.0, 19.2, [], None)       # nothing by the end (30)
     r = _run(done + [stuck], done + [stuck])
     # ttft: nine of 1.0 s and one censored at 30 - 19 = 11 s
-    assert ttft_p90_s.read(r) == pytest.approx(11.0)
-    assert latency_p90_s.read(r) == pytest.approx(11.0)
+    assert ttft_p50_s.read(r) == pytest.approx(1.0)
+    assert latency_p50_s.read(r) == pytest.approx(1.5)
     assert queue_wait_p90_s.read(r) == pytest.approx(11.0)
+    # six of ten stuck, censored at 30 - due: 11, 12, ..., 16 s; the
+    # median (sorted index 5) is the second of them
+    many = [_flight(19.0 - k, 19.2 - k, [], None) for k in range(6)]
+    r = _run(done[:4] + many, done[:4] + many)
+    assert ttft_p50_s.read(r) == pytest.approx(12.0)
+    assert latency_p50_s.read(r) == pytest.approx(12.0)
     r = _run(done, done)
-    assert ttft_p90_s.read(r) == pytest.approx(1.0)
-    assert latency_p90_s.read(r) == pytest.approx(1.5)
+    assert ttft_p50_s.read(r) == pytest.approx(1.0)
+    assert latency_p50_s.read(r) == pytest.approx(1.5)
     assert queue_wait_p90_s.read(r) == pytest.approx(0.5)
 
 

@@ -39,6 +39,9 @@ def test_every_cell_finds_its_files():
         assert cell.config["name"] == w["config"]
         assert "setup_s" in cell.end_to_end
         assert cell.per_layer and len(cell.end_to_end) >= 2
+        # each per-layer metric moves an end-to-end metric its cell reports
+        moves = {m["name"]: m["moves"] for m in spec["per_layer"]}
+        assert {moves[n] for n in cell.per_layer} <= set(cell.end_to_end)
         assert cell.params["replicas"] <= w["chips"]
 
 

@@ -7,7 +7,7 @@ import pytest
 
 from bench import trace as tr
 from bench.metrics import paged_decode_roofline, step_mfu
-from bench.model import Dims
+from bench.archs.dense_decoder import Dims
 from bench.work import Step, decode_attention, head_flops, least_time, \
     step_flops
 
@@ -17,7 +17,7 @@ PEAKS = {"bf16_flops_per_s": 100.0, "hbm_bytes_per_s": 10.0}
 
 
 def test_decode_attention_by_hand():
-    flops, byts = decode_attention(DIMS, [3, 5])
+    flops, byts = decode_attention(DIMS, 0, [3, 5])
     assert flops == 4 * 4 * 2 * 8               # 4 H D per key, 8 keys
     assert byts == 2 * 2 * 2 * 2 * 8 + 2 * 4 * 2 * 2 * 2
     assert least_time(flops, byts, PEAKS) == pytest.approx(19.2)
@@ -26,7 +26,7 @@ def test_decode_attention_by_hand():
 def test_step_flops_by_hand():
     # 4 tokens through 576 matmul weights a layer; attention over the
     # chunk's causal contexts 1 + 2 + 3 and one decode lane of 5
-    assert DIMS.layer_matmul_params() == 8 * 2 * 12 + 3 * 8 * 16
+    assert DIMS.layer_matmul_params(0) == 8 * 2 * 12 + 3 * 8 * 16
     assert step_flops(DIMS, Step([(0, 3)], [5])) == 2 * (
         2 * 576 * 4 + 4 * 4 * 2 * 11)
     # a chunk starting at 10 sees 11, 12 keys
